@@ -631,7 +631,8 @@ def population_scale(rounds=3, cohorts=(64, 256, 1024, 4096), chunk=64,
         the memory evidence vanish silently."""
         try:
             mem = eng._segment_fn(eng.config.chunk_rounds).lower(
-                carry, jnp.asarray(0, jnp.int32)).compile().memory_analysis()
+                carry, jnp.asarray(0, jnp.int32),
+                eng.sampler).compile().memory_analysis()
             return sum(int(getattr(mem, f, 0) or 0) for f in
                        ("argument_size_in_bytes", "temp_size_in_bytes",
                         "output_size_in_bytes"))

@@ -47,6 +47,7 @@ import numpy as np
 from repro.comm.accountant import GaussianAccountant
 from repro.comm.quantize import (payload_bytes as quant_payload_bytes,
                                  qmax_for_bits, quant_dequant_payload)
+from repro.kernels.ops import on_tpu
 
 F32 = jnp.float32
 
@@ -190,9 +191,9 @@ class QuantizedChannel(Channel):
 
     def encode_decode(self, ctx, tree_k, phase):
         impl = "jnp" if self.kernel == "off" else self.kernel
-        if impl == "pallas" and jax.default_backend() == "cpu":
-            # same policy as the engine's stats_kernel="pallas": fall back
-            # to the (exact) interpreter so the flag works everywhere
+        if impl == "pallas" and not on_tpu():
+            # same policy as the engine's stats_kernel="pallas": the
+            # (exact) interpreter off the TPU
             impl = "interpret"
         # one fused pass over the whole payload tree — same wire semantics
         # (per-client per-tensor scales) as quantizing leaf by leaf, but

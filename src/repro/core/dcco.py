@@ -66,22 +66,6 @@ def dcco_loss_shard_map_local(zf_local, zg_local, lam: float, axis_names) -> jnp
     return cco.cco_loss_from_stats(combined, lam)
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: new releases expose ``jax.shard_map``
-    with ``check_vma``; older ones ``jax.experimental.shard_map`` with
-    ``check_rep``. Replication checking is disabled either way (the DCCO
-    bodies make outputs replicated via explicit psums)."""
-    import inspect
-    try:
-        from jax import shard_map as sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    check_kw = "check_vma" if "check_vma" in params else "check_rep"
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{check_kw: False})
-
-
 def make_shard_map_dcco_loss(mesh, lam: float, data_axes=("data",)):
     """Returns loss_fn(zf, zg) where zf/zg are batch-sharded global arrays.
 
@@ -91,9 +75,10 @@ def make_shard_map_dcco_loss(mesh, lam: float, data_axes=("data",)):
     """
     pspec = P(data_axes if len(data_axes) > 1 else data_axes[0], None)
 
+    # replication checking off: the body replicates its output via psum
     @functools.partial(
-        shard_map_compat, mesh=mesh,
-        in_specs=(pspec, pspec), out_specs=P())
+        jax.shard_map, mesh=mesh,
+        in_specs=(pspec, pspec), out_specs=P(), check_vma=False)
     def loss_fn(zf, zg):
         loss = dcco_loss_shard_map_local(zf, zg, lam, data_axes)
         return loss[None] if loss.ndim == 0 else loss

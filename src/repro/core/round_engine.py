@@ -62,10 +62,11 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.tree_util import Partial
 
 from repro.core import fed_sim
-from repro.core.dcco import shard_map_compat
+from repro.kernels.ops import on_tpu
 from repro.server import drift as drift_lib
 from repro.server import update as server_update_lib
 
@@ -295,10 +296,9 @@ def _resolve_agg_stats_fn(cfg: EngineConfig, objective) -> Optional[Callable]:
         return None
     second = objective.second_moments
     if cfg.stats_kernel == "pallas":
-        # pallas only compiles on accelerator backends; CPU falls back to
-        # the (slow but exact) interpreter so the flag works everywhere
-        return make_kernel_agg_stats(
-            interpret=jax.default_backend() == "cpu", second_moments=second)
+        # compiled on a TPU, the (slow but exact) interpreter elsewhere
+        return make_kernel_agg_stats(interpret=not on_tpu(),
+                                     second_moments=second)
     if cfg.stats_kernel == "interpret":
         return make_kernel_agg_stats(interpret=True, second_moments=second)
     raise ValueError(f"unknown stats_kernel {cfg.stats_kernel!r}")
@@ -497,10 +497,10 @@ def stats_round_sharded(encoder_apply: Callable, params, opt_state,
         extra_specs += (P(), p_axis)
         out_specs += (p_axis, P())        # slot variates sharded, agg_dc
                                           # replicated like any aggregate
-    sharded = shard_map_compat(
-        local_body, mesh,
+    sharded = jax.shard_map(
+        local_body, mesh=mesh,
         in_specs=(P(), p_axis, p_axis) + extra_specs,
-        out_specs=out_specs)
+        out_specs=out_specs, check_vma=False)
     outs = sharded(params, client_data, client_sizes, *extra_args)
     avg_delta, loss, agg = outs[:3]
 
@@ -664,12 +664,13 @@ def make_round_body(encoder_apply: Callable, server_opt, cfg: EngineConfig,
 def make_streaming_round_body(encoder_apply: Callable, server_opt,
                               cfg: EngineConfig, sampler) -> Callable:
     """Build the streaming round body: round_fn(params, opt_state, drift,
-    k_sel, k_aug, key) -> (params, opt_state, drift, metrics). Unlike the
-    materialized bodies it samples INSIDE the round, one cohort chunk at a
-    time, so the engine never holds more than ``cfg.cohort_chunk`` clients
-    of batch data — the `sampler` must be chunkable
-    (``FederatedDataset.make_streaming_sampler`` /
-    ``repro.hierarchy.StreamingSampler``)."""
+    k_sel, k_aug, key, sampler) -> (params, opt_state, drift, metrics).
+    Unlike the materialized bodies it samples INSIDE the round, one cohort
+    chunk at a time, so the engine never holds more than
+    ``cfg.cohort_chunk`` clients of batch data — the `sampler` must be
+    chunkable (``FederatedDataset.make_streaming_sampler`` /
+    ``repro.hierarchy.StreamingSampler``). It is checked here and passed
+    again per call, as the segment's argument."""
     from repro.hierarchy import streaming as streaming_lib
 
     if cfg.algorithm != "dcco":
@@ -708,7 +709,7 @@ def make_streaming_round_body(encoder_apply: Callable, server_opt,
                   else server_opt)
     channel = cfg.channel
 
-    def round_fn(params, opt_state, drift, k_sel, k_aug, key):
+    def round_fn(params, opt_state, drift, k_sel, k_aug, key, sampler):
         sizes = sampler.cohort_sizes(k_sel)
         # per-round O(K)-scalar sampling state, hoisted out of both
         # phase scans
@@ -889,6 +890,10 @@ class RoundEngine:
 
     ``sampler(k_sel, k_aug) -> (batch, sizes)`` must be jax-traceable: it
     runs INSIDE the scan body (see FederatedDataset.make_round_sampler).
+    The sampler is an argument of each jitted segment: the dataset's
+    samplers are pytrees whose leaves are the staged arrays, so the
+    compiled program holds no dataset-sized constants; a plain function
+    is wrapped as ``jax.tree_util.Partial(fn)``, with no leaves.
     A full experiment is ceil(R / chunk_rounds) XLA program invocations
     instead of R Python dispatches.
     """
@@ -921,7 +926,14 @@ class RoundEngine:
         self._retrieval_template = None  # eval_shape of retrieval_eval,
                                          # resolved lazily on first run()
         self.config = config
-        self.sampler = sampler
+        self.sampler = (Partial(sampler) if callable(sampler) and
+                        not isinstance(sampler, Partial) else sampler)
+        if mesh is not None:
+            # the staged dataset lives on every mesh device from the start:
+            # left on the default device, each segment call would copy it
+            # out to the mesh again
+            self.sampler = jax.device_put(self.sampler,
+                                          NamedSharding(mesh, P()))
         self.drift_state = None      # final drift carry of the last run()
         self.buffer_state = None     # final AsyncState of the last run()
         self.cluster_state = None    # final ClusterState of the last run()
@@ -1024,7 +1036,8 @@ class RoundEngine:
         self._donate = donate
 
     # -- one scan segment ---------------------------------------------------
-    def _run_segment(self, num_rounds: int, carry: EngineCarry, start):
+    def _run_segment(self, num_rounds: int, carry: EngineCarry, start,
+                     sampler):
         def body(c, r):
             rkey = jax.random.fold_in(c.rng, r)
             k_sel, k_aug = jax.random.split(rkey)
@@ -1034,7 +1047,7 @@ class RoundEngine:
             k_ch = jax.random.fold_in(rkey, _CHANNEL_SALT)
             buffer, cluster = c.buffer, c.cluster
             if self._async_real:
-                batch, sizes, delays = self.sampler(k_sel, k_aug)
+                batch, sizes, delays = sampler(k_sel, k_aug)
                 params, opt_state, drift, buffer, m = self.round_fn(
                     c.params, c.opt_state, c.drift, c.buffer, batch, sizes,
                     delays, k_ch)
@@ -1043,10 +1056,11 @@ class RoundEngine:
                 # the streaming body samples inside the round, one cohort
                 # chunk at a time — the full batch never materializes here
                 params, opt_state, drift, m = self.round_fn(
-                    c.params, c.opt_state, c.drift, k_sel, k_aug, k_ch)
+                    c.params, c.opt_state, c.drift, k_sel, k_aug, k_ch,
+                    sampler)
                 applied, stale = jnp.ones((), F32), jnp.zeros((), F32)
             elif self._clustered:
-                batch, sizes = self.sampler(k_sel, k_aug)
+                batch, sizes = sampler(k_sel, k_aug)
                 params, opt_state, cluster, m = self.round_fn(
                     c.params, c.opt_state, c.cluster, batch, sizes, k_ch)
                 drift = c.drift
@@ -1055,9 +1069,9 @@ class RoundEngine:
                 if self._async:
                     # collapsed async config: same cohorts (delays are a
                     # fold_in side stream off k_sel), sync round body
-                    batch, sizes, _delays = self.sampler(k_sel, k_aug)
+                    batch, sizes, _delays = sampler(k_sel, k_aug)
                 else:
-                    batch, sizes = self.sampler(k_sel, k_aug)
+                    batch, sizes = sampler(k_sel, k_aug)
                 params, opt_state, drift, m = self.round_fn(
                     c.params, c.opt_state, c.drift, batch, sizes, k_ch)
                 applied, stale = jnp.ones((), F32), jnp.zeros((), F32)
@@ -1227,7 +1241,8 @@ class RoundEngine:
         while done < rounds:
             seg = min(chunk, rounds - done)
             carry, m = self._segment_fn(seg)(
-                carry, jnp.asarray(start_round + done, jnp.int32))
+                carry, jnp.asarray(start_round + done, jnp.int32),
+                self.sampler)
             done += seg
             for col, v in zip(cols, m):
                 # the retrieval field is a dict of per-round arrays (or ()

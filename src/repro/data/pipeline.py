@@ -10,6 +10,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
 from repro.data import augment
 from repro.data import partition as partition_lib
@@ -143,40 +144,54 @@ class FederatedDataset:
 
     # ------------------------------------------------- in-scan sampling --
 
-    def _stage(self):
+    def staged(self):
         """Device-resident (data, client_index, client_sizes), staged once
-        per dataset and shared by every in-scan sampler."""
+        per dataset and shared by every in-scan sampler. Each data leaf is
+        stored as (N, features) rows (``_gather`` restores the per-sample
+        shape): a TPU tiles an array's two minor dims to (8, 128), so
+        32x32x3 images kept 4-D would pad their channel dim 3 -> 128."""
         if self._staged is None:
-            self._staged = ({k: jnp.asarray(v) for k, v in self.data.items()},
+            self._staged = ({k: jnp.asarray(v.reshape(v.shape[0], -1))
+                             for k, v in self.data.items()},
                             jnp.asarray(self.client_index),
                             jnp.asarray(self.client_sizes, jnp.int32))
         return self._staged
+
+    def _gather(self, data, idx):
+        """Samples ``idx`` of the staged data rows, in per-sample shape."""
+        return {kk: v[idx].reshape(idx.shape + self.data[kk].shape[1:])
+                for kk, v in data.items()}
 
     def make_round_sampler(self, clients_per_round: int):
         """A jax-traceable ``sampler(k_sel, k_aug) -> (batch, sizes)``.
 
         The whole dataset and client index are staged onto device once per
-        dataset (cached, shared by all samplers); the returned closure does
-        cohort selection, gather, and the two-view augmentation with pure
-        jax ops, so it can run INSIDE a scan body
-        (repro.core.round_engine). Assumes the dataset fits in device
-        memory — the paper's decentralized corpora are small; for a corpus
-        larger than device memory, use the host-gathering ``round_batch``.
+        dataset (cached, shared by all samplers); the sampler does cohort
+        selection, gather, and the two-view augmentation with pure jax
+        ops, so it can run INSIDE a scan body (repro.core.round_engine).
+        It is a ``jax.tree_util.Partial`` whose leaves are the staged
+        arrays: passed to a jitted function as an argument, the dataset is
+        an input of the compiled program, not a constant folded into it (a
+        50,000-image corpus would be ~600 MB of literals). Assumes the
+        dataset fits in device memory — the paper's decentralized corpora
+        are small; for a corpus larger than device memory, use the
+        host-gathering ``round_batch``.
         """
         if clients_per_round in self._samplers:
             return self._samplers[clients_per_round]
-        data, cindex, csizes = self._stage()
         num_clients, n = self.num_clients, self.samples_per_client
         k_round = clients_per_round
 
-        def sampler(k_sel, k_aug):
+        def sample(staged, k_sel, k_aug):
+            data, cindex, csizes = staged
             sel = jax.random.choice(k_sel, num_clients, (k_round,),
                                     replace=False)
             idx = cindex[sel].reshape(-1)                    # (K*n,)
-            gathered = {kk: v[idx] for kk, v in data.items()}
+            gathered = self._gather(data, idx)
             out = self._two_views(k_aug, gathered, k_round, n)
             return out, csizes[sel]
 
+        sampler = Partial(sample, self.staged())
         self._samplers[clients_per_round] = sampler
         return sampler
 
@@ -196,15 +211,15 @@ class FederatedDataset:
         """
         from repro.data import latency as latency_lib
         model = latency_lib.resolve_latency(latency)
-        data, cindex, csizes = self._stage()
         num_clients, n = self.num_clients, self.samples_per_client
         k_round = clients_per_round
 
-        def sampler(k_sel, k_aug):
+        def sample(staged, k_sel, k_aug):
+            data, cindex, csizes = staged
             sel = jax.random.choice(k_sel, num_clients, (k_round,),
                                     replace=False)
             idx = cindex[sel].reshape(-1)                    # (K*n,)
-            gathered = {kk: v[idx] for kk, v in data.items()}
+            gathered = self._gather(data, idx)
             out = self._two_views(k_aug, gathered, k_round, n)
             sizes = csizes[sel]
             dk = jax.random.fold_in(k_sel, latency_lib._LATENCY_SALT)
@@ -212,6 +227,7 @@ class FederatedDataset:
                                                sel.astype(jnp.int32))
             return out, sizes, delays
 
+        sampler = Partial(sample, self.staged())
         sampler.latency = model
         sampler.clients_per_round = k_round
         return sampler
@@ -227,14 +243,15 @@ class FederatedDataset:
         clients of batch data. Chunks concatenate to exactly the cohort
         ``make_round_sampler`` would emit for the same keys (same
         selection, same per-sample augmentation keys — tested), which is
-        what makes streaming-vs-materialized equivalence checkable.
+        what makes streaming-vs-materialized equivalence checkable. As
+        there, the staged arrays are leaves of the sampler (its callables
+        are ``Partial``s over them), not constants of a compiled program.
         """
         from repro.hierarchy.streaming import StreamingSampler
         if cohort_chunk < 1 or clients_per_round % cohort_chunk:
             raise ValueError(
                 f"clients_per_round={clients_per_round} does not divide "
                 f"into chunks of {cohort_chunk}")
-        data, cindex, csizes = self._stage()
         num_clients, n = self.num_clients, self.samples_per_client
         k_round, chunk = clients_per_round, cohort_chunk
 
@@ -243,22 +260,25 @@ class FederatedDataset:
                                     replace=False)
             return sel, jax.random.split(k_aug, k_round * n)
 
-        def sample_chunk(state, c):
+        def sample_chunk(staged, state, c):
+            data, cindex, csizes = staged
             sel, aug_keys = state
             sel_c = jax.lax.dynamic_slice(sel, (c * chunk,), (chunk,))
             idx = cindex[sel_c].reshape(-1)                  # (chunk*n,)
-            gathered = {kk: v[idx] for kk, v in data.items()}
+            gathered = self._gather(data, idx)
             keys = jax.lax.dynamic_slice(aug_keys, (c * chunk * n, 0),
                                          (chunk * n, 2))
             batch = self._two_views_keyed(keys, gathered, chunk, n)
             return batch, csizes[sel_c]
 
-        def cohort_sizes(k_sel):
+        def cohort_sizes(csizes, k_sel):
             # recomputes the cohort selection (same key -> same choice as
             # prepare), so variable-size clients report true sizes here too
             sel = jax.random.choice(k_sel, num_clients, (k_round,),
                                     replace=False)
             return csizes[sel]
 
-        return StreamingSampler(k_round, chunk, prepare, sample_chunk,
-                                cohort_sizes)
+        staged = self.staged()
+        return StreamingSampler(k_round, chunk, Partial(prepare),
+                                Partial(sample_chunk, staged),
+                                Partial(cohort_sizes, staged[2]))

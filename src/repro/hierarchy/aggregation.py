@@ -47,6 +47,7 @@ import jax.numpy as jnp
 
 from repro.comm.channel import Channel, ChannelContext, DenseChannel
 from repro.kernels import ref as kernels_ref
+from repro.kernels.ops import on_tpu
 from repro.kernels.segment_sum import segment_sum_pallas
 
 F32 = jnp.float32
@@ -78,8 +79,8 @@ def fold_to_edges(tree_k, weights, seg_ids, num_edges: int,
     All leaves are flattened and concatenated into ONE (K, D) row matrix
     so the whole stats dict folds in a single pass — the jnp path through
     ``jax.ops.segment_sum``, the kernel paths through
-    ``segment_sum_pallas`` (``"pallas"`` falls back to the interpreter on
-    CPU, same policy as the engine's stats_kernel flag)."""
+    ``segment_sum_pallas`` (``"pallas"`` runs under the interpreter off
+    the TPU, same policy as the engine's stats_kernel flag)."""
     if impl not in FOLD_IMPLS:
         raise ValueError(f"unknown fold impl {impl!r}; "
                          f"expected one of {FOLD_IMPLS}")
@@ -92,7 +93,7 @@ def fold_to_edges(tree_k, weights, seg_ids, num_edges: int,
         folded = kernels_ref.segment_sum_ref(rows, seg_ids, num_edges,
                                              weights)
     else:
-        interpret = impl == "interpret" or jax.default_backend() == "cpu"
+        interpret = impl == "interpret" or not on_tpu()
         folded = segment_sum_pallas(rows, seg_ids, num_edges, weights,
                                     interpret=interpret)
     out, off = [], 0

@@ -33,7 +33,9 @@ and compiled peak memory vs chunk size).
 """
 from __future__ import annotations
 
-from typing import Callable, NamedTuple
+import dataclasses
+import functools
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +46,11 @@ from repro.server import update as server_update_lib
 F32 = jnp.float32
 
 
-class StreamingSampler(NamedTuple):
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["prepare", "sample_chunk", "cohort_sizes"],
+                   meta_fields=["clients_per_round", "cohort_chunk"])
+@dataclasses.dataclass(frozen=True)
+class StreamingSampler:
     """A chunkable cohort sampler for the streaming engine path.
 
     ``prepare(k_sel, k_aug)`` computes the per-round O(K)-scalar state
@@ -56,6 +62,10 @@ class StreamingSampler(NamedTuple):
     replays it); ``cohort_sizes(k_sel)`` returns the full (K,) client
     sizes (channels need them for participation and Eq.-3 weights — the
     *batches* are what never materialize).
+    The sampler is a pytree, so the engine can pass it to its jitted
+    segment as an argument: the three callables are
+    ``jax.tree_util.Partial``s, whose bound arrays are its leaves (a plain
+    function is ``Partial(fn)``, with none).
     ``FederatedDataset.make_streaming_sampler`` builds one whose chunks
     concatenate to exactly ``make_round_sampler``'s cohort.
     """

@@ -35,6 +35,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 import jax.numpy as jnp
 
+from repro.kernels.ops import on_tpu
+
 F32 = jnp.float32
 I32 = jnp.int32
 NEG_INF = -1e30         # sentinel score for padded corpus rows / used slots
@@ -280,7 +282,7 @@ def mips_topk(q, corpus, k: int, *, backend: str = "auto",
               n_total: int | None = None):
     """Top-k maximum-inner-product search, backend-dispatched.
 
-    backend: "auto" (pallas on accelerators, chunked jnp on CPU) |
+    backend: "auto" (pallas on a TPU, chunked jnp elsewhere) |
     "pallas" | "interpret" (pallas under the interpreter) | "chunked".
     Returns ((Q, k) f32 scores, (Q, k) i32 corpus indices), descending
     score, ties by ascending index. Every path keeps peak memory at
@@ -293,7 +295,7 @@ def mips_topk(q, corpus, k: int, *, backend: str = "auto",
     bit-exact cross-shard merge on.
     """
     if backend == "auto":
-        backend = "chunked" if jax.default_backend() == "cpu" else "pallas"
+        backend = "pallas" if on_tpu() else "chunked"
     if backend in ("pallas", "interpret"):
         return mips_topk_pallas(q, corpus, k=k, block_q=block_q,
                                 block_n=block_n,

@@ -1,6 +1,6 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU the Mosaic kernels run natively; on CPU (this container) they run in
+On TPU the Mosaic kernels run natively; on any other backend they run in
 interpret mode so the whole stack stays executable. ``use_pallas=False``
 falls back to the pure-jnp reference (the path the XLA dry-run lowers).
 """
@@ -14,7 +14,10 @@ from repro.kernels.cco_stats import cco_stats_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 
 
-def _on_tpu() -> bool:
+def on_tpu() -> bool:
+    """The one platform test behind every ``"pallas"`` kernel flag: the
+    kernels are written for the TPU's Mosaic compiler, so ``"pallas"``
+    compiles them on a TPU and runs them under the interpreter elsewhere."""
     return jax.default_backend() == "tpu"
 
 
@@ -24,7 +27,7 @@ def cco_stats(zf, zg, *, use_pallas: bool = True, block_n: int = 512,
     if not use_pallas:
         return ref.cco_stats_ref(zf, zg)
     return cco_stats_pallas(zf, zg, block_n=block_n, block_d=block_d,
-                            interpret=not _on_tpu())
+                            interpret=not on_tpu())
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -35,4 +38,4 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   block_q=block_q, block_kv=block_kv,
-                                  interpret=not _on_tpu())
+                                  interpret=not on_tpu())

@@ -41,9 +41,14 @@ from repro.data import pipeline, synthetic
 from repro.launch import steps as steps_lib
 from repro.models import dual_encoder
 from repro.optim import optimizers as opt_lib, schedules
+from repro.retrieval import index as index_lib
 from repro.server import drift as drift_lib
 from repro.server import update as server_update_lib
 from repro.sharding import maybe_initialize_distributed
+from repro.utils import enable_compile_cache
+
+# images per chunk of the probe's feature forward (make_probe_features)
+PROBE_CHUNK = 1024
 
 
 def build_dataset(cfg, args):
@@ -395,15 +400,18 @@ def build_parser() -> argparse.ArgumentParser:
     g.add_argument("--stats-kernel", choices=["off", "pallas", "interpret"],
                    default="off",
                    help="route phase-1 aggregate stats through the fused "
-                        "Pallas kernel (engine mode; 'pallas' falls back "
-                        "to the interpreter on CPU)")
+                        "Pallas kernel (engine mode; 'pallas' compiles it "
+                        "on a TPU and interprets it elsewhere)")
     g.add_argument("--compute-dtype", default="float32",
                    choices=sorted(round_engine.COMPUTE_DTYPES),
                    help="encoder forward/backward compute dtype (engine "
                         "mode). 'bfloat16' halves activation traffic and "
                         "doubles MXU throughput; the Eq.-3 statistics "
                         "accumulation, parameters, and server state stay "
-                        "float32 regardless (see docs/performance.md)")
+                        "float32 regardless (see docs/performance.md). On "
+                        "a TPU, 'float32' matmuls and convolutions run at "
+                        "the chip's default precision (one bfloat16 pass) "
+                        "unless jax.default_matmul_precision says otherwise")
     g.add_argument("--cohort-chunk", type=int, default=0,
                    help="stream the cohort through each round in chunks "
                         "of this many clients (engine mode; peak memory "
@@ -533,13 +541,38 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main():
+def make_probe_features(cfg, chunk: int = PROBE_CHUNK):
+    """Jitted ``(tower_params, images) -> (N, d_model)`` ResNet features
+    for the linear probe, encoded ``chunk`` images at a time: activation
+    memory is O(chunk), not O(dataset) (a one-shot forward over 50,000
+    CIFAR-sized images needs ~13 GB for the stem activations alone).
+    ``images`` may be (N, H, W, C) or the dataset's staged (N, H*W*C)
+    rows."""
+    from repro.models import resnet as resnet_mod
+    shape = (cfg.image_size, cfg.image_size, cfg.resnet_in_channels)
+
+    def forward(tower, images):
+        return index_lib.encode_corpus_chunked(
+            lambda t, x: resnet_mod.resnet_forward(
+                cfg, t, x.reshape((-1,) + shape)),
+            tower, images, chunk=chunk, normalize=False)
+
+    return jax.jit(forward)
+
+
+def main(argv=None):
+    """Run the trainer on ``argv`` (default: the command line). Returns
+    ``{"history": per-round losses, "probe": final probe accuracy,
+    "segment_seconds": wall seconds of each engine segment}`` (the last
+    is empty outside ``--mode engine``; the first segment includes its
+    compilation)."""
     # no-op unless the REPRO_COORDINATOR / REPRO_NUM_PROCESSES /
     # REPRO_PROCESS_ID launch contract is set (multi-host runs); must
     # happen before any jax device use
     maybe_initialize_distributed()
+    enable_compile_cache()
     ap = build_parser()
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     validate_flags(ap, args)
 
     objective = objectives_lib.get_objective(
@@ -587,17 +620,19 @@ def main():
         fused_step = jax.jit(steps_lib.make_dcco_train_step(
             cfg, de_cfg, tcfg, opt.opt, num_microbatches=args.micro))
 
+    if cfg.family == "resnet":
+        probe_features = make_probe_features(cfg)
+        probe_labels = jnp.asarray(labels)
+
     def evaluate(p):
         if cfg.family != "resnet":
             return float("nan")
-        from repro.models import resnet as resnet_mod
-        z = resnet_mod.resnet_forward(cfg, p["tower"],
-                                      jnp.asarray(ds.data["images"]))
-        n = len(labels)
-        cut = int(n * 0.7)
+        # the in-scan samplers' device copy of the images, not a new one
+        z = probe_features(p["tower"], ds.staged()[0]["images"])
+        cut = int(len(labels) * 0.7)
         return float(eval_lib.ridge_linear_probe(
-            z[:cut], jnp.asarray(labels[:cut]), z[cut:],
-            jnp.asarray(labels[cut:]), args.num_classes))
+            z[:cut], probe_labels[:cut], z[cut:], probe_labels[cut:],
+            args.num_classes))
 
     channel = comm.get_channel(
         args.channel, quant_bits=args.quant_bits,
@@ -614,6 +649,7 @@ def main():
 
     os.makedirs(args.ckpt_dir, exist_ok=True)
     history = []
+    segment_seconds = []
     t0 = time.time()
 
     if args.mode == "engine":
@@ -681,8 +717,11 @@ def main():
                       "by the synchronous engine) — starting the buffered "
                       "run with an empty buffer", flush=True)
 
+        t_seg = [time.time()]
+
         def on_segment(round_end, carry, m):
             history.extend(float(x) for x in np.asarray(m.loss))
+            segment_seconds.append(time.time() - t_seg[0])
             wire_total[0] += float(np.sum(np.asarray(m.wire_bytes)))
             acc = evaluate(carry.params)
             dt = time.time() - t0
@@ -705,6 +744,7 @@ def main():
                   f"enc_std={float(m.encoding_std[-1]):.4f} "
                   f"probe_acc={acc:.3f}{extra} "
                   f"({dt / (round_end - start_round):.2f}s/round)", flush=True)
+            t_seg[0] = time.time()
 
         params, opt_state, _ = engine.run(
             params, opt_state, jax.random.PRNGKey(args.seed),
@@ -712,8 +752,8 @@ def main():
             on_segment=on_segment, ckpt_dir=args.ckpt_dir,
             ckpt_every=args.ckpt_every, ckpt_name=args.arch,
             drift_state=drift_state, buffer_state=buffer_state)
-        _report(args, history, evaluate, params, channel, wire_total[0])
-        return
+        return _report(args, history, evaluate, params, channel,
+                       wire_total[0], segment_seconds)
 
     for r in range(start_round, args.rounds):
         rkey = jax.random.PRNGKey(args.seed * 100003 + r)
@@ -752,17 +792,22 @@ def main():
             if args.scaffold:
                 blob["drift"] = drift_state
             save_checkpoint(path, blob, r + 1)
-    _report(args, history, evaluate, params, channel, wire_total[0])
+    return _report(args, history, evaluate, params, channel, wire_total[0],
+                   segment_seconds)
 
 
-def _report(args, history, evaluate, params, channel=None, wire_bytes=0.0):
+def _report(args, history, evaluate, params, channel=None, wire_bytes=0.0,
+            segment_seconds=()):
+    probe = evaluate(params)
+    out = {"history": history, "probe": probe,
+           "segment_seconds": list(segment_seconds)}
     if history:
         print(f"final loss {history[-1]:.4f}; first {history[0]:.4f}; "
-              f"probe {evaluate(params):.3f}")
+              f"probe {probe:.3f}")
     else:
         print(f"no rounds to run (resumed at or past --rounds "
-              f"{args.rounds}); probe {evaluate(params):.3f}")
-        return
+              f"{args.rounds}); probe {probe:.3f}")
+        return out
     if channel is not None:
         line = f"channel {channel!r}: uplink {wire_bytes / 1e6:.3f} MB total"
         acct = getattr(channel, "accountant", None)
@@ -772,6 +817,7 @@ def _report(args, history, evaluate, params, channel=None, wire_bytes=0.0):
         print(line)
     with open(os.path.join(args.ckpt_dir, "history.json"), "w") as f:
         json.dump(history, f)
+    return out
 
 
 if __name__ == "__main__":

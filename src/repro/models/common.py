@@ -77,14 +77,25 @@ def layernorm(p, x, eps: float = 1e-6):
 
 
 def groupnorm(x, num_groups: int, scale, bias, eps: float = 1e-5):
-    """GroupNorm over channel-last input (..., C). Paper Sec 4.2 (Wu & He)."""
-    *lead, c = x.shape
+    """GroupNorm (Wu & He; paper Sec 4.2) over channel-last input
+    (B, ..., C): per sample, each group of C/G channels is normalized over
+    those channels and all positions between the batch and channel axes.
+    The moments are pooled from per-channel (B, C) sums, so no array
+    carries a minor dim of C/G (2..8 here, padded to 128 lanes on a TPU)."""
+    b, c = x.shape[0], x.shape[-1]
     assert c % num_groups == 0
-    xf = x.astype(F32).reshape(*lead, num_groups, c // num_groups)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    xf = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (xf.reshape(*lead, c) * scale + bias).astype(x.dtype)
+    xf = x.astype(F32)
+    bshape = (b,) + (1,) * (x.ndim - 2) + (c,)
+
+    def group_mean(t):                            # (B, ..., C) -> bshape
+        m = jnp.mean(t, axis=tuple(range(1, t.ndim - 1)))          # (B, C)
+        m = jnp.mean(m.reshape(b, num_groups, c // num_groups), axis=-1)
+        return jnp.repeat(m, c // num_groups, axis=-1).reshape(bshape)
+
+    mu = group_mean(xf)
+    xc = xf - mu
+    xf = xc * jax.lax.rsqrt(group_mean(jnp.square(xc)) + eps)
+    return (xf * scale + bias).astype(x.dtype)
 
 
 # ------------------------------------------------------------------- rope ---

@@ -145,8 +145,6 @@ def make_shard_map_loss(objective: StatsObjective, mesh,
     loss exactly (shard_map's transpose psums the per-shard grads)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.dcco import shard_map_compat
-
     pspec = P(data_axes if len(data_axes) > 1 else data_axes[0], None)
 
     def local_loss(zf_local, zg_local):
@@ -155,8 +153,8 @@ def make_shard_map_loss(objective: StatsObjective, mesh,
         loss = objective.loss_from_stats(objective.combine(local, agg))
         return loss[None] if loss.ndim == 0 else loss
 
-    sharded = shard_map_compat(local_loss, mesh,
-                               in_specs=(pspec, pspec), out_specs=P())
+    sharded = jax.shard_map(local_loss, mesh=mesh, in_specs=(pspec, pspec),
+                            out_specs=P(), check_vma=False)
 
     def wrapped(zf, zg):
         return sharded(zf, zg).reshape(())

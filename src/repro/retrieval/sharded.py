@@ -115,8 +115,6 @@ def sharded_mips_topk(q, shards, k: int, *, n_total: int, mesh=None,
         vals, idxs = jax.vmap(local)(shards, offsets)       # (S, Q, k)
         return merge_topk(vals, idxs, k)
 
-    from repro.core.dcco import shard_map_compat
-
     def shard_body(q_rep, shards_loc):
         off = jax.lax.axis_index(axis).astype(I32) * shard_size
         v, i = mips_topk(q_rep, shards_loc[0], k, backend=backend,
@@ -125,8 +123,8 @@ def sharded_mips_topk(q, shards, k: int, *, n_total: int, mesh=None,
         i = jax.lax.all_gather(i, axis)
         return merge_topk(v, i, k)
 
-    fn = shard_map_compat(shard_body, mesh,
-                          in_specs=(P(), P(axis)), out_specs=(P(), P()))
+    fn = jax.shard_map(shard_body, mesh=mesh, in_specs=(P(), P(axis)),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(q, shards)
 
 

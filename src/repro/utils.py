@@ -1,7 +1,9 @@
-"""Small shared utilities: pytree helpers, dtype policy, rng streams."""
+"""Small shared utilities: pytree helpers, dtype policy, rng streams, the
+persistent compile cache."""
 from __future__ import annotations
 
 import functools
+import os
 from typing import Any
 
 import jax
@@ -84,3 +86,24 @@ def has_nan(tree: PyTree) -> bool:
 def chunked(seq, n):
     for i in range(0, len(seq), n):
         yield seq[i:i + n]
+
+
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <checkout>/.jax_cache (git-ignored): a fixed path, because the directory
+# is part of what lets a later run find the entries an earlier one wrote
+REPO_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has read it at import
+    and nothing is set here; otherwise the cache goes to
+    :data:`REPO_COMPILE_CACHE` inside the checkout. Call it before the
+    first compile (the trainer's ``main`` and ``chip_smoke.py`` do)."""
+    if os.environ.get(COMPILE_CACHE_ENV):
+        return os.environ[COMPILE_CACHE_ENV]
+    jax.config.update("jax_compilation_cache_dir", REPO_COMPILE_CACHE)
+    return REPO_COMPILE_CACHE

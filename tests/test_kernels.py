@@ -58,6 +58,26 @@ class TestCcoStatsKernel:
                                        np.asarray(expected[k]),
                                        rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.parametrize("moments", ["cross", "full"])
+    def test_tpu_pipeline_semantics(self, moments, rng_key):
+        """Under the TPU interpreter, which models the TPU pipeline (an
+        output block is written back when the grid leaves it and never
+        read again, so revisiting it on non-consecutive steps is an
+        error), three d tiles still give the oracle's statistics."""
+        from jax.experimental.pallas import tpu as pltpu
+        k1, k2 = jax.random.split(rng_key)
+        zf = jax.random.normal(k1, (256, 384), jnp.float32)
+        zg = zf + 0.5 * jax.random.normal(k2, (256, 384), jnp.float32)
+        with pltpu.force_tpu_interpret_mode(pltpu.InterpretParams()):
+            out = cco_stats_pallas(zf, zg, block_n=128, block_d=128,
+                                   moments=moments)
+        expected = ref.cco_stats_ref(zf, zg,
+                                     second_moments=moments == "full")
+        for k in expected:
+            np.testing.assert_allclose(np.asarray(out[k]),
+                                       np.asarray(expected[k]),
+                                       rtol=1e-4, atol=1e-5)
+
     def test_unknown_moment_set_rejected(self, rng_key):
         z = jax.random.normal(rng_key, (8, 8), jnp.float32)
         with pytest.raises(ValueError):

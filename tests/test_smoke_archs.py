@@ -103,3 +103,25 @@ def test_resnet_smoke(rng_key):
     z, _ = dual_encoder.encode(cfg, de, params, {"images": imgs})
     assert z.shape == (4, 32)
     assert not bool(jnp.isnan(z).any())
+
+
+@pytest.mark.parametrize("groups", [1, 4, 8])
+def test_groupnorm_pools_space_and_group_channels(groups, rng_key):
+    """The ResNet's GroupNorm (Wu & He) normalizes each sample's group of
+    C/G channels over those channels AND every spatial position — not over
+    each pixel's C/G channels alone — and no sample sees another."""
+    import numpy as np
+    from repro.models.common import groupnorm
+    b, h, w, c = 3, 5, 6, 16
+    x = jax.random.normal(rng_key, (b, h, w, c)) * 3.0 + 1.0
+    scale = jnp.linspace(0.5, 1.5, c)
+    bias = jnp.linspace(-1.0, 1.0, c)
+    out = np.asarray(groupnorm(x, groups, scale, bias))
+    xr = np.asarray(x, np.float64).reshape(b, h, w, groups, c // groups)
+    mu = xr.mean(axis=(1, 2, 4), keepdims=True)
+    var = xr.var(axis=(1, 2, 4), keepdims=True)
+    want = ((xr - mu) / np.sqrt(var + 1e-5)).reshape(b, h, w, c)
+    want = want * np.asarray(scale) + np.asarray(bias)
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    alone = np.asarray(groupnorm(x[1:2], groups, scale, bias))
+    np.testing.assert_allclose(alone, out[1:2], rtol=1e-6, atol=1e-6)

@@ -128,3 +128,59 @@ def test_checkpoint_resume_federated_training(tmp_path, rng_key):
                                    opt, batch, sizes)
     assert utils.tree_max_abs_diff(utils.tree_cast(p_a, jnp.float32),
                                    utils.tree_cast(p_b, jnp.float32)) < 1e-7
+
+
+def test_chunked_probe_matches_one_shot_forward(rng_key):
+    """The trainer's probe encodes the dataset in chunks (O(chunk) memory)
+    and equals one forward over all images, for 4-D images and for the
+    dataset's staged (N, H*W*C) rows alike; 70 images in chunks of 32 also
+    exercise the padded last chunk."""
+    from repro.launch import train
+    cfg, _, params, _, embed = _resnet_setup(rng_key)
+    imgs, _ = synthetic.synthetic_labeled_images(
+        70, 5, image_size=cfg.image_size, noise=0.5, seed=3)
+    one_shot = embed(params, jnp.asarray(imgs))
+    probe = train.make_probe_features(cfg, chunk=32)
+    for x in (imgs, imgs.reshape(len(imgs), -1)):
+        np.testing.assert_allclose(np.asarray(probe(params["tower"], x)),
+                                   np.asarray(one_shot), rtol=1e-5,
+                                   atol=1e-5)
+
+
+def test_train_main_takes_argv(tmp_path, monkeypatch):
+    """``train.main(argv)`` runs the engine in-process (the chip smoke's
+    entry point) and returns the per-round losses, the probe, and one wall
+    time per scan segment."""
+    from repro.launch import train
+    # set: JAX read no cache dir at import, so this run caches nothing
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    out = train.main(["--rounds", "4", "--chunk-rounds", "2",
+                      "--eval-every", "2", "--dataset-size", "64",
+                      "--clients-per-round", "4",
+                      "--ckpt-dir", str(tmp_path / "ck")])
+    assert len(out["history"]) == 4
+    assert np.isfinite(out["history"]).all()
+    assert len(out["segment_seconds"]) == 2
+    assert 0.0 <= out["probe"] <= 1.0
+
+
+def test_compile_cache_placed_from_outside(monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set nothing is configured in code;
+    unset, the cache goes to one fixed, git-ignored path in the checkout."""
+    import os
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(utils.COMPILE_CACHE_ENV, "/elsewhere")
+    assert utils.enable_compile_cache() == "/elsewhere"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv(utils.COMPILE_CACHE_ENV)
+    try:
+        assert utils.enable_compile_cache() == utils.REPO_COMPILE_CACHE
+        assert jax.config.jax_compilation_cache_dir == \
+            utils.REPO_COMPILE_CACHE
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    root = os.path.dirname(utils.REPO_COMPILE_CACHE)
+    assert os.path.isfile(os.path.join(root, "chip_smoke.py"))
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert os.path.basename(utils.REPO_COMPILE_CACHE) + "/" in \
+            f.read().split()
