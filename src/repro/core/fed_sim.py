@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import utils
-from repro.core import cco, losses
+from repro.core import cco, losses, phases
 from repro.optim import optimizers as opt_lib
 from repro.server import drift as drift_lib
 from repro.server import update as server_update_lib
@@ -221,20 +221,24 @@ def stats_round(encoder_apply: Callable, params, opt_state, server_opt,
             zf, zg = encoder_apply(params, batch)
             return objective.stats_masked(zf, zg, mask)
 
-        st_k = jax.vmap(client_stats)(client_data, masks)
-        if ctx is None:
-            agg = cco.weighted_average_stats(st_k, client_sizes.astype(F32))
-        else:
-            agg = channel.aggregate(ctx, st_k, "stats")
+        with jax.named_scope(phases.PHASE1):
+            st_k = jax.vmap(client_stats)(client_data, masks)
+            if ctx is None:
+                agg = cco.weighted_average_stats(st_k,
+                                                 client_sizes.astype(F32))
+            else:
+                agg = channel.aggregate(ctx, st_k, "stats")
     else:
         if ctx is not None and not channel.supports_flat_stats:
             raise ValueError(
                 f"agg_stats_fn needs per-client payloads, incompatible "
                 f"with {channel!r}")
-        zf_k, zg_k = jax.vmap(lambda b: encoder_apply(params, b))(client_data)
-        agg = agg_stats_fn(zf_k.reshape(-1, zf_k.shape[-1]),
-                           zg_k.reshape(-1, zg_k.shape[-1]),
-                           masks.reshape(-1))
+        with jax.named_scope(phases.PHASE1):
+            zf_k, zg_k = jax.vmap(lambda b: encoder_apply(params, b))(
+                client_data)
+            agg = agg_stats_fn(zf_k.reshape(-1, zf_k.shape[-1]),
+                               zg_k.reshape(-1, zg_k.shape[-1]),
+                               masks.reshape(-1))
     if ctx is not None:
         wire = wire + channel.round_bytes(ctx, agg)
 
@@ -249,19 +253,24 @@ def stats_round(encoder_apply: Callable, params, opt_state, server_opt,
         return client_local_steps(loss_fn, params, client_lr, local_steps,
                                   prox_mu=prox_mu, correction=corr)
 
-    if scaffold_state is None:
-        deltas, losses_k = jax.vmap(client_update)(client_data, masks)
-    else:
-        deltas, losses_k = jax.vmap(client_update)(
-            client_data, masks, drift_lib.scaffold_corrections(scaffold_state))
+    with jax.named_scope(phases.PHASE2):
+        if scaffold_state is None:
+            deltas, losses_k = jax.vmap(client_update)(client_data, masks)
+        else:
+            deltas, losses_k = jax.vmap(client_update)(
+                client_data, masks,
+                drift_lib.scaffold_corrections(scaffold_state))
 
     # ---- server: weighted average of deltas -> FedOpt pseudo-gradient
-    if ctx is None:
-        avg_delta = jax.tree.map(lambda d: jnp.tensordot(w, d, axes=1), deltas)
-    else:
-        avg_delta = channel.aggregate(ctx, deltas, "update")
-        wire = wire + channel.round_bytes(ctx, avg_delta)
-    params, opt_state = server_update.step(params, opt_state, avg_delta)
+    with jax.named_scope(phases.DELTA_AVERAGE):
+        if ctx is None:
+            avg_delta = jax.tree.map(lambda d: jnp.tensordot(w, d, axes=1),
+                                     deltas)
+        else:
+            avg_delta = channel.aggregate(ctx, deltas, "update")
+            wire = wire + channel.round_bytes(ctx, avg_delta)
+    with jax.named_scope(phases.SERVER_UPDATE):
+        params, opt_state = server_update.step(params, opt_state, avg_delta)
 
     # collapse probe on the aggregated stats
     enc_std = objective.encoding_std(agg)

@@ -62,10 +62,11 @@ from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.tree_util import Partial
 
-from repro.core import fed_sim
+from repro.core import fed_sim, phases
 from repro.kernels.ops import on_tpu
 from repro.server import drift as drift_lib
 from repro.server import update as server_update_lib
@@ -415,21 +416,23 @@ def stats_round_sharded(encoder_apply: Callable, params, opt_state,
             zf, zg = encoder_apply(p, batch)
             return objective.stats_masked(zf, zg, mask)
 
-        st_k = jax.vmap(client_stats)(batch_l, masks)
-        if ctx_l is None:
-            agg = {k: jax.lax.psum(jnp.tensordot(w_l, v, axes=1), axis)
-                   for k, v in st_k.items()}
-        else:
-            # channel.local_fold is this shard's partial aggregate of the
-            # decoded payloads (the base fold is the same tensordot as
-            # above; a hierarchical channel folds its shard-local edges
-            # here, kernels/segment_sum.py); the psum is the server hop
-            dec = channel.encode_decode(ctx_l, st_k, "stats")
-            part = channel.local_fold(ctx_l, dec, "stats",
-                                      num_shards=nshards)
-            agg = {k: jax.lax.psum(v, axis) for k, v in part.items()}
-            agg = channel.post_aggregate(
-                ctx_l._replace(key=ckey), agg, "stats")
+        with jax.named_scope(phases.PHASE1):
+            st_k = jax.vmap(client_stats)(batch_l, masks)
+            if ctx_l is None:
+                agg = {k: jax.lax.psum(jnp.tensordot(w_l, v, axes=1), axis)
+                       for k, v in st_k.items()}
+            else:
+                # channel.local_fold is this shard's partial aggregate of
+                # the decoded payloads (the base fold is the same tensordot
+                # as above; a hierarchical channel folds its shard-local
+                # edges here, kernels/segment_sum.py); the psum is the
+                # server hop
+                dec = channel.encode_decode(ctx_l, st_k, "stats")
+                part = channel.local_fold(ctx_l, dec, "stats",
+                                          num_shards=nshards)
+                agg = {k: jax.lax.psum(v, axis) for k, v in part.items()}
+                agg = channel.post_aggregate(
+                    ctx_l._replace(key=ckey), agg, "stats")
 
         def client_update(batch, mask, corr=None):
             def loss_fn(pp):
@@ -441,23 +444,27 @@ def stats_round_sharded(encoder_apply: Callable, params, opt_state,
                                               local_steps, prox_mu=prox_mu,
                                               correction=corr)
 
-        if scaffold_state is None:
-            deltas, losses_k = jax.vmap(client_update)(batch_l, masks)
-        else:
-            deltas, losses_k = jax.vmap(client_update)(
-                batch_l, masks, drift_lib.scaffold_corrections(state_l))
+        with jax.named_scope(phases.PHASE2):
+            if scaffold_state is None:
+                deltas, losses_k = jax.vmap(client_update)(batch_l, masks)
+            else:
+                deltas, losses_k = jax.vmap(client_update)(
+                    batch_l, masks, drift_lib.scaffold_corrections(state_l))
         raw_deltas = deltas
-        if ctx_l is None:
-            avg_delta = jax.tree.map(
-                lambda d: jax.lax.psum(jnp.tensordot(w_l, d, axes=1), axis),
-                deltas)
-        else:
-            dec_d = channel.encode_decode(ctx_l, deltas, "update")
-            part_d = channel.local_fold(ctx_l, dec_d, "update",
-                                        num_shards=nshards)
-            avg_delta = jax.tree.map(lambda d: jax.lax.psum(d, axis), part_d)
-            avg_delta = channel.post_aggregate(
-                ctx_l._replace(key=ckey), avg_delta, "update")
+        with jax.named_scope(phases.DELTA_AVERAGE):
+            if ctx_l is None:
+                avg_delta = jax.tree.map(
+                    lambda d: jax.lax.psum(jnp.tensordot(w_l, d, axes=1),
+                                           axis),
+                    deltas)
+            else:
+                dec_d = channel.encode_decode(ctx_l, deltas, "update")
+                part_d = channel.local_fold(ctx_l, dec_d, "update",
+                                            num_shards=nshards)
+                avg_delta = jax.tree.map(lambda d: jax.lax.psum(d, axis),
+                                         part_d)
+                avg_delta = channel.post_aggregate(
+                    ctx_l._replace(key=ckey), avg_delta, "update")
         loss = jax.lax.psum(jnp.sum(w_l * losses_k), axis)
         outs = (avg_delta, loss[None], agg)
         if scaffold_state is not None:
@@ -504,7 +511,8 @@ def stats_round_sharded(encoder_apply: Callable, params, opt_state,
     outs = sharded(params, client_data, client_sizes, *extra_args)
     avg_delta, loss, agg = outs[:3]
 
-    params, opt_state = server_update.step(params, opt_state, avg_delta)
+    with jax.named_scope(phases.SERVER_UPDATE):
+        params, opt_state = server_update.step(params, opt_state, avg_delta)
     enc_std = objective.encoding_std(agg)
     wire = 0.0
     if channel is not None:
@@ -710,10 +718,11 @@ def make_streaming_round_body(encoder_apply: Callable, server_opt,
     channel = cfg.channel
 
     def round_fn(params, opt_state, drift, k_sel, k_aug, key, sampler):
-        sizes = sampler.cohort_sizes(k_sel)
-        # per-round O(K)-scalar sampling state, hoisted out of both
-        # phase scans
-        state = sampler.prepare(k_sel, k_aug)
+        with jax.named_scope(phases.SAMPLE):
+            sizes = sampler.cohort_sizes(k_sel)
+            # per-round O(K)-scalar sampling state, hoisted out of both
+            # phase scans
+            state = sampler.prepare(k_sel, k_aug)
         p, o, m = streaming_lib.streaming_stats_round(
             encoder_apply, params, opt_state, server_opt,
             lambda c: sampler.sample_chunk(state, c),
@@ -1047,7 +1056,8 @@ class RoundEngine:
             k_ch = jax.random.fold_in(rkey, _CHANNEL_SALT)
             buffer, cluster = c.buffer, c.cluster
             if self._async_real:
-                batch, sizes, delays = sampler(k_sel, k_aug)
+                with jax.named_scope(phases.SAMPLE):
+                    batch, sizes, delays = sampler(k_sel, k_aug)
                 params, opt_state, drift, buffer, m = self.round_fn(
                     c.params, c.opt_state, c.drift, c.buffer, batch, sizes,
                     delays, k_ch)
@@ -1060,18 +1070,20 @@ class RoundEngine:
                     sampler)
                 applied, stale = jnp.ones((), F32), jnp.zeros((), F32)
             elif self._clustered:
-                batch, sizes = sampler(k_sel, k_aug)
+                with jax.named_scope(phases.SAMPLE):
+                    batch, sizes = sampler(k_sel, k_aug)
                 params, opt_state, cluster, m = self.round_fn(
                     c.params, c.opt_state, c.cluster, batch, sizes, k_ch)
                 drift = c.drift
                 applied, stale = jnp.ones((), F32), jnp.zeros((), F32)
             else:
-                if self._async:
-                    # collapsed async config: same cohorts (delays are a
-                    # fold_in side stream off k_sel), sync round body
-                    batch, sizes, _delays = sampler(k_sel, k_aug)
-                else:
-                    batch, sizes = sampler(k_sel, k_aug)
+                with jax.named_scope(phases.SAMPLE):
+                    if self._async:
+                        # collapsed async config: same cohorts (delays are
+                        # a fold_in side stream off k_sel), sync round body
+                        batch, sizes, _delays = sampler(k_sel, k_aug)
+                    else:
+                        batch, sizes = sampler(k_sel, k_aug)
                 params, opt_state, drift, m = self.round_fn(
                     c.params, c.opt_state, c.drift, batch, sizes, k_ch)
                 applied, stale = jnp.ones((), F32), jnp.zeros((), F32)
@@ -1234,21 +1246,25 @@ class RoundEngine:
             # segments donate their carry; copy once so the CALLER's buffers
             # survive the run (donation then recycles only engine-internal
             # buffers from segment to segment).
-            carry = jax.tree.map(jnp.copy, carry)
+            with TraceAnnotation(phases.CARRY_COPY):
+                carry = jax.tree.map(jnp.copy, carry)
         chunk = self.config.chunk_rounds
         cols = [[] for _ in EngineMetrics._fields]
         done, last_ckpt = 0, 0
         while done < rounds:
             seg = min(chunk, rounds - done)
-            carry, m = self._segment_fn(seg)(
-                carry, jnp.asarray(start_round + done, jnp.int32),
-                self.sampler)
+            with TraceAnnotation(phases.DISPATCH):
+                carry, m = self._segment_fn(seg)(
+                    carry, jnp.asarray(start_round + done, jnp.int32),
+                    self.sampler)
             done += seg
-            for col, v in zip(cols, m):
-                # the retrieval field is a dict of per-round arrays (or ()
-                # when unused); everything else is a plain (seg,) array
-                col.append(v if isinstance(v, (dict, tuple))
-                           else jnp.asarray(v, F32))
+            with TraceAnnotation(phases.COLLECT):
+                for col, v in zip(cols, m):
+                    # the retrieval field is a dict of per-round arrays (or
+                    # () when unused); everything else is a plain (seg,)
+                    # array
+                    col.append(v if isinstance(v, (dict, tuple))
+                               else jnp.asarray(v, F32))
             round_end = start_round + done
             if on_segment is not None:
                 on_segment(round_end, carry, m)
@@ -1262,7 +1278,8 @@ class RoundEngine:
                     blob["buffer"] = carry.buffer
                 if self._clustered:
                     blob["cluster"] = carry.cluster
-                save_checkpoint(path, blob, round_end)
+                with TraceAnnotation(phases.CHECKPOINT):
+                    save_checkpoint(path, blob, round_end)
                 last_ckpt = done
         self.drift_state = carry.drift if self.config.scaffold else None
         self.buffer_state = carry.buffer if self._async_real else None
@@ -1271,15 +1288,16 @@ class RoundEngine:
             # host-side bookkeeping (e.g. the DP epsilon accountant)
             self.config.channel.finalize_rounds(done)
         fields = []
-        for name, col in zip(EngineMetrics._fields, cols):
-            if name == "retrieval":
-                if col and isinstance(col[0], dict):
-                    fields.append({k: jnp.concatenate([c[k] for c in col])
-                                   for k in col[0]})
+        with TraceAnnotation(phases.COLLECT):
+            for name, col in zip(EngineMetrics._fields, cols):
+                if name == "retrieval":
+                    if col and isinstance(col[0], dict):
+                        fields.append({k: jnp.concatenate([c[k] for c in col])
+                                       for k in col[0]})
+                    else:
+                        fields.append({})
                 else:
-                    fields.append({})
-            else:
-                fields.append(jnp.concatenate(col) if col
-                              else jnp.zeros((0,)))
+                    fields.append(jnp.concatenate(col) if col
+                                  else jnp.zeros((0,)))
         metrics = EngineMetrics(*fields)
         return carry.params, carry.opt_state, metrics

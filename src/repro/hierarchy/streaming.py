@@ -40,7 +40,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.core import fed_sim
+from repro.core import fed_sim, phases
 from repro.server import update as server_update_lib
 
 F32 = jnp.float32
@@ -114,7 +114,8 @@ def streaming_stats_round(encoder_apply: Callable, params, opt_state,
         return jax.lax.dynamic_slice(w, (c * chunk,), (chunk,))
 
     def chunk_stats(c):
-        batch, sizes_c = sample_chunk(c)
+        with jax.named_scope(phases.SAMPLE):
+            batch, sizes_c = sample_chunk(c)
         n_pad = jax.tree.leaves(batch)[0].shape[1]
         masks = fed_sim._client_masks(sizes_c, n_pad)
 
@@ -131,20 +132,23 @@ def streaming_stats_round(encoder_apply: Callable, params, opt_state,
     # ---- phase 1: stream the chunks, accumulate the stat partials.
     # Chunk 0 runs outside the scan and seeds the carry — no zero
     # templates to derive, and a 1-chunk cohort never builds a scan.
-    acc0 = chunk_stats(0)
-    if num_chunks > 1:
-        agg_sum, _ = jax.lax.scan(
-            lambda acc, c: (jax.tree.map(jnp.add, acc, chunk_stats(c)),
-                            None),
-            acc0, jnp.arange(1, num_chunks))
-    else:
-        agg_sum = acc0
-    agg = agg_sum if ctx is None else channel.post_aggregate(ctx, agg_sum,
-                                                             "stats")
+    with jax.named_scope(phases.PHASE1):
+        acc0 = chunk_stats(0)
+        if num_chunks > 1:
+            agg_sum, _ = jax.lax.scan(
+                lambda acc, c: (jax.tree.map(jnp.add, acc, chunk_stats(c)),
+                                None),
+                acc0, jnp.arange(1, num_chunks))
+        else:
+            agg_sum = acc0
+        agg = agg_sum if ctx is None else channel.post_aggregate(
+            ctx, agg_sum, "stats")
 
-    # ---- phase 2: stream again, clients step against the combine
+    # ---- phase 2: stream again, clients step against the combine; the
+    # weighted partial sums of the deltas are the delta average
     def chunk_update(c):
-        batch, sizes_c = sample_chunk(c)
+        with jax.named_scope(phases.SAMPLE):
+            batch, sizes_c = sample_chunk(c)
         n_pad = jax.tree.leaves(batch)[0].shape[1]
         masks = fed_sim._client_masks(sizes_c, n_pad)
 
@@ -160,28 +164,33 @@ def streaming_stats_round(encoder_apply: Callable, params, opt_state,
 
         deltas, losses_k = jax.vmap(client_update)(batch, masks)
         wc = w_slice(c)
-        if ctx is None:
-            part = jax.tree.map(lambda d: jnp.tensordot(wc, d, axes=1),
-                                deltas)
-        else:
-            part = channel.chunk_fold(ctx, deltas, "update", c, wc)
+        with jax.named_scope(phases.DELTA_AVERAGE):
+            if ctx is None:
+                part = jax.tree.map(lambda d: jnp.tensordot(wc, d, axes=1),
+                                    deltas)
+            else:
+                part = channel.chunk_fold(ctx, deltas, "update", c, wc)
         return part, jnp.sum(wc * losses_k)
 
-    d0, l0 = chunk_update(0)
-    if num_chunks > 1:
-        def p2_body(carry, c):
-            part, lo = chunk_update(c)
-            return (jax.tree.map(jnp.add, carry[0], part),
-                    carry[1] + lo), None
+    with jax.named_scope(phases.PHASE2):
+        d0, l0 = chunk_update(0)
+        if num_chunks > 1:
+            def p2_body(carry, c):
+                part, lo = chunk_update(c)
+                with jax.named_scope(phases.DELTA_AVERAGE):
+                    delta_sum = jax.tree.map(jnp.add, carry[0], part)
+                return (delta_sum, carry[1] + lo), None
 
-        (delta_sum, loss), _ = jax.lax.scan(p2_body, (d0, l0),
-                                            jnp.arange(1, num_chunks))
-    else:
-        delta_sum, loss = d0, l0
-    avg_delta = delta_sum if ctx is None else channel.post_aggregate(
-        ctx, delta_sum, "update")
+            (delta_sum, loss), _ = jax.lax.scan(p2_body, (d0, l0),
+                                                jnp.arange(1, num_chunks))
+        else:
+            delta_sum, loss = d0, l0
+    with jax.named_scope(phases.DELTA_AVERAGE):
+        avg_delta = delta_sum if ctx is None else channel.post_aggregate(
+            ctx, delta_sum, "update")
 
-    params, opt_state = server_update.step(params, opt_state, avg_delta)
+    with jax.named_scope(phases.SERVER_UPDATE):
+        params, opt_state = server_update.step(params, opt_state, avg_delta)
     enc_std = objective.encoding_std(agg)
     wire = 0.0
     if ctx is not None:

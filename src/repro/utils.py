@@ -100,9 +100,15 @@ def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has read it at import
-    and nothing is set here; otherwise the cache goes to
-    :data:`REPO_COMPILE_CACHE` inside the checkout. Call it before the
-    first compile (the trainer's ``main`` and ``chip_smoke.py`` do)."""
+    and the directory is not set here; otherwise the cache goes to
+    :data:`REPO_COMPILE_CACHE` inside the checkout. Either way a cache key
+    includes the program's metadata: JAX's default key strips it, and a
+    program that differs from a cached one only in its ``op_name``s (the
+    round's named scopes, ``repro.core.phases``) would load that one's
+    executable, whose op names a profiler trace then shows. Call it
+    before the first compile (the trainer's ``main`` and
+    ``chip_smoke.py`` do)."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if os.environ.get(COMPILE_CACHE_ENV):
         return os.environ[COMPILE_CACHE_ENV]
     jax.config.update("jax_compilation_cache_dir", REPO_COMPILE_CACHE)

@@ -184,3 +184,40 @@ def test_compile_cache_placed_from_outside(monkeypatch):
     with open(os.path.join(root, ".gitignore")) as f:
         assert os.path.basename(utils.REPO_COMPILE_CACHE) + "/" in \
             f.read().split()
+
+
+def test_compile_cache_keys_carry_metadata(monkeypatch, tmp_path):
+    """A program that differs from a cached one only in its named scopes
+    compiles its own executable, so a trace shows its own op names (JAX's
+    default key strips them, and the cached executable's would show)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_compilation_cache_include_metadata_in_key")
+    before = {n: getattr(jax.config, n) for n in names}
+
+    def program(scope):
+        def step(x):
+            with jax.named_scope(scope):
+                return jnp.sin(x) * 2.0
+        return jax.jit(step)
+
+    monkeypatch.setenv(utils.COMPILE_CACHE_ENV, str(tmp_path))
+    try:
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          False)
+        cc.reset_cache()
+        utils.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        x = jnp.ones(3)
+        program("round.phase1").lower(x).compile()
+        assert "round.phase2" in program("round.phase2").lower(x) \
+            .compile().as_text()
+    finally:
+        for n, v in before.items():
+            jax.config.update(n, v)
+        cc.reset_cache()
