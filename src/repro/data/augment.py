@@ -13,19 +13,46 @@ import jax.numpy as jnp
 
 # ------------------------------------------------------------------ images --
 
+def crop_resize_flip(img, top, left, flip, crop_frac: float = 0.8):
+    """The (ch, cw) crop at (top, left), nearest-resized back to (h, w)
+    and mirrored left-right where ``flip``. img: (H,W,C).
+
+    Offsets, resize map and flip fold into one source row and one source
+    column a pixel, selected by one-hot products ``R @ X @ P`` on the
+    image's (H, W*C) rows, with no gather and no dynamic slice.
+    """
+    h, w, c = img.shape
+    ch, cw = int(h * crop_frac), int(w * crop_frac)
+    rows = top + (jnp.arange(h) * ch // h)
+    j = jnp.arange(w)
+    cols = left + (jnp.where(flip, w - 1 - j, j) * cw // w)
+    sel_rows = (rows[:, None] == jnp.arange(h)[None, :]).astype(img.dtype)
+    # column one-hot expanded over channels: (W*C, w*c)
+    sel_cols = ((cols[None, :] == j[:, None])[:, None, :, None]
+                & jnp.eye(c, dtype=bool)[None, :, None, :])
+    sel_cols = sel_cols.reshape(w * c, w * c).astype(img.dtype)
+    hi = jax.lax.Precision.HIGHEST
+    out = jnp.dot(jnp.dot(sel_rows, img.reshape(h, w * c), precision=hi),
+                  sel_cols, precision=hi)
+    return out.reshape(h, w, c)
+
+
 def augment_image(key, img, crop_frac: float = 0.8):
-    """Random crop-and-resize (nearest), flip, color jitter. img: (H,W,C)."""
+    """Random crop-and-resize (nearest), flip, color jitter. img: (H,W,C).
+
+    The crop, resize and flip are ``crop_resize_flip``'s one-hot products:
+    under ``vmap`` a ``dynamic_slice`` becomes a gather that XLA on the TPU
+    runs as a serial loop of one ``dynamic-update-slice`` a sample. They
+    run at HIGHEST, where a one-hot product is exact in f32; the TPU's
+    default precision (one bf16 pass) would round every pixel to bf16.
+    """
     kc, kf, kb, kcon = jax.random.split(key, 4)
     h, w, c = img.shape
     ch, cw = int(h * crop_frac), int(w * crop_frac)
     top = jax.random.randint(kc, (), 0, h - ch + 1)
     left = jax.random.randint(kc, (), 0, w - cw + 1)
-    crop = jax.lax.dynamic_slice(img, (top, left, 0), (ch, cw, c))
-    # nearest-neighbour resize back to (h, w)
-    ridx = (jnp.arange(h) * ch // h).astype(jnp.int32)
-    cidx = (jnp.arange(w) * cw // w).astype(jnp.int32)
-    out = crop[ridx][:, cidx]
-    out = jnp.where(jax.random.bernoulli(kf), out[:, ::-1], out)
+    out = crop_resize_flip(img, top, left, jax.random.bernoulli(kf),
+                           crop_frac)
     brightness = 1.0 + 0.4 * (jax.random.uniform(kb) - 0.5)
     contrast = 1.0 + 0.4 * (jax.random.uniform(kcon) - 0.5)
     mean = out.mean()

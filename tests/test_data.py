@@ -1,6 +1,8 @@
 """Data substrate: Dirichlet non-IID partitioning (Hsu et al. process),
 partition strategies as data (PartitionSpec), stateless two-view
 augmentations, federated pipeline layouts."""
+import functools
+import re
 import warnings
 
 import jax
@@ -209,7 +211,83 @@ class TestPartitionSpec:
         assert int(jnp.max(sizes_scan)) <= 4
 
 
+def _oracle_crop(img, top, left, flip, crop_frac):
+    """The crop by slice and index gathers that ``crop_resize_flip``
+    replaced, kept as its reference."""
+    h, w, c = img.shape
+    ch, cw = int(h * crop_frac), int(w * crop_frac)
+    crop = jax.lax.dynamic_slice(img, (top, left, 0), (ch, cw, c))
+    ridx = (jnp.arange(h) * ch // h).astype(jnp.int32)
+    cidx = (jnp.arange(w) * cw // w).astype(jnp.int32)
+    out = crop[ridx][:, cidx]
+    return jnp.where(flip, out[:, ::-1], out)
+
+
+def _oracle_augment(key, img, crop_frac=0.8):
+    kc, kf, kb, kcon = jax.random.split(key, 4)
+    h, w, c = img.shape
+    ch, cw = int(h * crop_frac), int(w * crop_frac)
+    top = jax.random.randint(kc, (), 0, h - ch + 1)
+    left = jax.random.randint(kc, (), 0, w - cw + 1)
+    out = _oracle_crop(img, top, left, jax.random.bernoulli(kf), crop_frac)
+    brightness = 1.0 + 0.4 * (jax.random.uniform(kb) - 0.5)
+    contrast = 1.0 + 0.4 * (jax.random.uniform(kcon) - 0.5)
+    mean = out.mean()
+    return jnp.clip((out - mean) * contrast + mean * brightness, 0.0, 1.0)
+
+
+IMAGE_SHAPES = [(32, 32, 3), (16, 16, 3), (24, 16, 1)]
+
+
 class TestAugment:
+    @pytest.mark.parametrize("flip", [False, True])
+    @pytest.mark.parametrize("crop_frac", [0.5, 0.8, 1.0])
+    @pytest.mark.parametrize("shape", IMAGE_SHAPES)
+    def test_crop_matches_slice_oracle_bitwise(self, shape, crop_frac, flip):
+        """The one-hot crop equals the slice-and-gather crop bit for bit
+        at every (top, left) offset."""
+        h, w, _ = shape
+        ch, cw = int(h * crop_frac), int(w * crop_frac)
+        tops, lefts = np.meshgrid(np.arange(h - ch + 1), np.arange(w - cw + 1),
+                                  indexing="ij")
+        tops, lefts = tops.ravel(), lefts.ravel()
+        imgs = jax.random.uniform(jax.random.PRNGKey(len(tops)),
+                                  (len(tops),) + shape)
+        flips = jnp.full(len(tops), flip)
+        new = jax.jit(jax.vmap(functools.partial(
+            augment.crop_resize_flip, crop_frac=crop_frac)))(
+                imgs, tops, lefts, flips)
+        old = jax.jit(jax.vmap(functools.partial(
+            _oracle_crop, crop_frac=crop_frac)))(imgs, tops, lefts, flips)
+        np.testing.assert_array_equal(np.asarray(new), np.asarray(old))
+
+    @pytest.mark.parametrize("shape", IMAGE_SHAPES)
+    def test_two_views_match_oracle(self, shape):
+        """Whole views: the same keys give the oracle's views up to the
+        mean's reduction order."""
+        keys = jax.random.split(jax.random.PRNGKey(7), 64)
+        imgs = jax.random.uniform(jax.random.PRNGKey(8), (64,) + shape)
+        new = jax.jit(jax.vmap(augment.two_views_image))(keys, imgs)
+
+        def oracle(key, img):
+            k1, k2 = jax.random.split(key)
+            return _oracle_augment(k1, img), _oracle_augment(k2, img)
+
+        old = jax.jit(jax.vmap(oracle))(keys, imgs)
+        for a, b in zip(new, old):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=0, atol=1e-6)
+
+    def test_batched_views_have_no_gather_or_dynamic_slice(self):
+        """A batched slice or gather is what XLA on the TPU turns into a
+        serial per-sample loop; the compiled views hold neither."""
+        hlo = jax.jit(jax.vmap(augment.two_views_image)).lower(
+            jax.ShapeDtypeStruct((512, 2), jnp.uint32),
+            jax.ShapeDtypeStruct((512, 32, 32, 3), jnp.float32),
+        ).compile().as_text()
+        for op in ("gather", "dynamic-slice", "dynamic-update-slice"):
+            assert not re.search(rf"\s{op}\(", hlo), op
+
     def test_stateless_determinism(self, rng_key):
         img = jax.random.uniform(rng_key, (16, 16, 3))
         a1 = augment.augment_image(jax.random.PRNGKey(5), img)

@@ -1,4 +1,5 @@
-"""Compile-only checks of every Pallas kernel against the TPU compiler.
+"""Compile-only checks of every Pallas kernel, and of the sampler's image
+views, against the TPU compiler.
 
 Each test lowers and compiles a kernel at a real width for one chip of a
 described v5e (``v5e:2x2``) — no chip is attached, nothing runs — and
@@ -13,12 +14,14 @@ imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.data import augment
 from repro.kernels.cco_stats import cco_stats_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.mips_topk import mips_topk_pallas
@@ -95,3 +98,17 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = KERNELS[name]
     text = _compile(fn, one_chip, *shapes)
     assert "tpu_custom_call" in text, f"{name}: no Mosaic kernel compiled"
+
+
+def test_image_views_compile_without_loop_for_v5e(one_chip):
+    """One round's 512 views (r14-k256-n2) compile for the TPU with no
+    gather and no loop (a vmapped ``dynamic_slice`` crop compiles to a
+    ``while`` of one ``dynamic-update-slice`` a sample there), and every
+    selection product at HIGHEST, where a one-hot product is exact."""
+    text = _compile(jax.vmap(augment.two_views_image), one_chip,
+                    ((512, 2), jnp.uint32), ((512, 32, 32, 3), F32))
+    for op in ("gather", "while", "dynamic-update-slice"):
+        assert not re.search(rf"\s{op}\(", text), op
+    products = re.findall(r"\s(?:convolution|dot)\(.*", text)
+    assert products
+    assert all("operand_precision={highest,highest}" in p for p in products)
