@@ -8,6 +8,13 @@ cell is an entry of ``BENCHMARK.json``; its files are found by name:
 ``bench/workloads/<cell>.json`` (engine settings and the limits of the
 output check), ``bench/configs/<config>.json`` (the model) and
 ``bench/traffic/<traffic>.json`` (data, partition, cohort, optimizer).
+The configuration names its code: its ``"family"`` is the module
+``bench/families/<family>.py`` (the program's model config, the data's
+per-sample leaves, the FLOPs of a sample), and its ``"reference"`` the
+path of its plain encoder, views and initialisation, which
+``bench/protocol.py`` drives through the round. So a configuration of a
+new architecture comes as new files: family, reference, config, traffic,
+workload, and any per-layer readers under ``bench/metrics/``.
 
 Set-up makes the data and the parameters from the seed, builds the round
 engine as the trainer does (``repro.launch.train.main``), and drives it
@@ -15,10 +22,10 @@ through its first segments, the ones the output check follows. The
 window then calls ``RoundEngine.run`` for whole segments until
 ``--seconds`` have passed, waiting for each segment's losses. Once the
 window has closed and the program's state is freed, the plain reference
-(``bench/reference.py``) follows the first rounds again and decides
-``correct``. The last line of standard output is one JSON object; with
-``--trace 1`` it carries the cell's per-layer metrics, read from a
-profiler trace of the window by the files under ``bench/metrics/``.
+follows the first rounds again and decides ``correct``. The last line of
+standard output is one JSON object; with ``--trace 1`` it carries the
+cell's per-layer metrics, read from a profiler trace of the window by the
+files under ``bench/metrics/``.
 """
 from __future__ import annotations
 
@@ -29,6 +36,7 @@ T_START = time.perf_counter()
 import argparse  # noqa: E402
 import gc  # noqa: E402
 import importlib  # noqa: E402
+import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -48,7 +56,6 @@ for _p in (os.path.join(ROOT, "src"), ROOT):
 
 import numpy as np  # noqa: E402
 
-from bench import flops as flops_lib  # noqa: E402
 from bench import traffic as traffic_lib  # noqa: E402
 
 TRACE_DIR = os.path.join(ROOT, ".bench_traces")
@@ -65,29 +72,53 @@ class NoChip(RuntimeError):
 
 # -- the cell, by name ----------------------------------------------------------
 
-def _read(*parts):
-    with open(os.path.join(BENCH_DIR, *parts)) as f:
+def _read(root, *parts):
+    with open(os.path.join(root, *parts)) as f:
         return json.load(f)
 
 
 def load_cell(name: str, root: str = ROOT) -> dict:
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+    bench = _read(root, "BENCHMARK.json")
     entry = {w["name"]: w for w in bench["workloads"]}[name]
-    cell = _read("workloads", name + ".json")
-    model = _read("configs", entry["config"] + ".json")
-    traffic = _read("traffic", entry["traffic"] + ".json")
+    cell = _read(root, "bench", "workloads", name + ".json")
+    model = _read(root, "bench", "configs", entry["config"] + ".json")
+    traffic = _read(root, "bench", "traffic", entry["traffic"] + ".json")
 
     def mine(metrics):
         return [m for m in metrics if name in m.get("workloads", [name])]
 
-    return {"name": name, "entry": entry, "cell": cell, "model": model,
-            "traffic": traffic, "end_to_end": mine(bench["end_to_end"]),
+    return {"name": name, "root": root, "entry": entry, "cell": cell,
+            "model": model, "traffic": traffic,
+            "end_to_end": mine(bench["end_to_end"]),
             "per_layer": mine(bench["per_layer"])}
 
 
+def load_module(root: str, path: str):
+    """The Python file at ``path`` (relative to ``root``), imported once
+    per process."""
+    path = os.path.abspath(os.path.join(root, path))
+    name = "bench_file_" + "".join(c if c.isalnum() else "_" for c in path)
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(name, path)
+        module = importlib.util.module_from_spec(spec)
+        sys.modules[name] = module
+        spec.loader.exec_module(module)
+    return sys.modules[name]
+
+
+def family(spec: dict):
+    """The configuration's family module, ``bench/families/<family>.py``."""
+    return load_module(spec["root"], os.path.join(
+        "bench", "families", spec["model"]["family"] + ".py"))
+
+
+def reference(spec: dict):
+    """The configuration's plain encoder, views and initialisation."""
+    return load_module(spec["root"], spec["model"]["reference"])
+
+
 def read_peaks(device_kind: str) -> dict:
-    peaks = _read("peaks.json")
+    peaks = _read(BENCH_DIR, "peaks.json")
     if device_kind not in peaks:
         raise KeyError(f"device kind {device_kind!r} is not in "
                        f"bench/peaks.json; add its peaks with their source")
@@ -120,23 +151,6 @@ class CompileClock:
 
 # -- building the system under test, as the trainer does ---------------------------
 
-def model_config(model: dict):
-    from repro.configs.base import DualEncoderConfig, ModelConfig
-    cfg = ModelConfig(
-        name=model["name"], family="resnet", source=model["source"],
-        num_layers=model["num_layers"], d_model=model["resnet_channels"][-1],
-        num_heads=1, num_kv_heads=1, d_ff=0, vocab_size=0,
-        resnet_stages=tuple(model["resnet_stages"]),
-        resnet_channels=tuple(model["resnet_channels"]),
-        resnet_groups=model["resnet_groups"],
-        resnet_in_channels=model["resnet_in_channels"],
-        image_size=model["image_size"], dtype=model["dtype"])
-    de_cfg = DualEncoderConfig(proj_dims=tuple(model["proj_dims"]),
-                               lambda_cco=model["lambda_cco"],
-                               shared_towers=True)
-    return cfg, de_cfg
-
-
 def build_engine(spec: dict, data, compute_dtype=None, server_hook=None):
     """The round engine and its server optimizer, built the way
     ``repro.launch.train.main`` builds them in engine mode;
@@ -149,7 +163,7 @@ def build_engine(spec: dict, data, compute_dtype=None, server_hook=None):
     from repro.server import update as server_update_lib
 
     model, tr, cell = spec["model"], spec["traffic"], spec["cell"]
-    cfg, de_cfg = model_config(model)
+    cfg, de_cfg = family(spec).model_config(model)
     srv = tr["server"]
     opt = server_update_lib.get_server_update(
         "fedavg_sgd", base_opt=opt_lib.adam(
@@ -168,8 +182,8 @@ def build_engine(spec: dict, data, compute_dtype=None, server_hook=None):
         channel=channel,
         compute_dtype=compute_dtype or model["compute_dtype"],
         server_update=opt)
-    h, w, c = data.image_shape
-    ds = FederatedDataset({"images": data.images.reshape(-1, h, w, c)},
+    ds = FederatedDataset({k: v.reshape((-1,) + data.shapes[k])
+                           for k, v in data.leaves.items()},
                           data.labels, data.client_index,
                           client_sizes=data.client_sizes)
     ds.staged()
@@ -183,15 +197,13 @@ def make_inputs(spec: dict, seed: int):
     """The cell's data and partition from the seed (host arrays)."""
     times = {}
     t = time.perf_counter()
-    images, labels = traffic_lib.make_dataset(spec["traffic"], spec["model"],
-                                              seed)
+    leaves, shapes, labels = family(spec).make_inputs(spec["traffic"],
+                                                      spec["model"], seed)
     times["data"] = time.perf_counter() - t
     t = time.perf_counter()
     index, sizes = traffic_lib.make_partition(spec["traffic"], labels, seed)
     times["partition"] = time.perf_counter() - t
-    s = spec["model"]["image_size"]
-    data = traffic_lib.Traffic(images, labels, index, sizes,
-                               (s, s, spec["model"]["resnet_in_channels"]))
+    data = traffic_lib.Traffic(leaves, shapes, labels, index, sizes)
     return data, times
 
 
@@ -203,10 +215,10 @@ def rounds_key(spec: dict, seed: int) -> np.ndarray:
         spec["traffic"].get("rounds_seed", seed), "rounds")
 
 
-def make_params(jax, model: dict, seed: int):
-    from bench import reference
+def make_params(jax, spec: dict, seed: int):
+    init_params, model = reference(spec).init_params, spec["model"]
     key = traffic_lib.jax_key_data(seed, "params")
-    init = jax.jit(lambda k: reference.init_params(k, model))
+    init = jax.jit(lambda k: init_params(k, model))
     return init(key)
 
 
@@ -300,11 +312,11 @@ def compare(spec, prog, ref) -> dict:
 
 
 def run_reference(jax, spec, data, seed, rounds, operand_dtype=None):
-    from bench import reference as ref_lib
-    p0 = make_params(jax, spec["model"], seed)
+    from bench import protocol
+    p0 = make_params(jax, spec, seed)
     rng = rounds_key(spec, seed)
-    ref = ref_lib.Reference(spec["model"], data, spec["traffic"],
-                            operand_dtype=operand_dtype)
+    ref = protocol.Reference(reference(spec), spec["model"], data,
+                             spec["traffic"], operand_dtype=operand_dtype)
     p, opt, losses, g1 = ref.follow(p0, rng, rounds)
     change = jax.tree.map(lambda a, b: a - b, p, p0)
     return {"losses": losses, "m": opt["m"], "v": opt["v"], "change": change,
@@ -369,7 +381,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     if engine_hook is not None:
         engine = engine_hook(engine)
     t = time.perf_counter()
-    params = make_params(jax, model, seed)
+    params = make_params(jax, spec, seed)
     opt_state = jax.jit(opt.init)(params)
     jax.block_until_ready(opt_state)
     phases["params"] = time.perf_counter() - t
@@ -380,7 +392,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
     t = time.perf_counter()
     params, opt_state, m = engine.run(params, opt_state, rng, chunk)
     losses = [float(x) for x in np.asarray(m.loss)]
-    p_init = make_params(jax, model, seed)
+    p_init = make_params(jax, spec, seed)
     prog = {"losses": losses,
             "m": jax.device_get(opt_state["m"]),
             "v": jax.device_get(opt_state["v"]),
@@ -449,7 +461,7 @@ def run_cell(spec: dict, seed: int, seconds: float, trace: bool, *,
         info["memory_peak_bytes"] = peak
         peaks = read_peaks(info["kind"]) if require_tpu else None
         sps = samples / elapsed
-        per_sample = flops_lib.model_flops_per_sample(model, tr["local_steps"])
+        per_sample = family(spec).flops_per_sample(model, tr["local_steps"])
         e2e = {"samples_per_s": (sps, "samples/s"),
                "mfu": (100.0 * sps * per_sample /
                        (chips * peaks["bf16_flops_per_s"]) if peaks else None,
@@ -549,13 +561,13 @@ def run_control(spec: dict, seed: int, *, require_tpu: bool = True) -> dict:
 def valid_samples(jax, jnp, data, tr, rng, first_round, rounds) -> int:
     """Valid client samples trained in rounds [first_round, +rounds): the
     sizes of each round's cohort, drawn as the engine draws it."""
-    from bench import reference as ref_lib
+    from bench import protocol
     sizes = jnp.asarray(data.client_sizes)
 
     def one(r):
-        k_sel, _ = ref_lib.round_keys(rng, r)
-        return jnp.sum(sizes[ref_lib.cohort(k_sel, data.num_clients,
-                                            tr["clients_per_round"])])
+        k_sel, _ = protocol.round_keys(rng, r)
+        return jnp.sum(sizes[protocol.cohort(k_sel, data.num_clients,
+                                             tr["clients_per_round"])])
 
     rs = jnp.arange(first_round, first_round + rounds)
     return int(jax.jit(jax.vmap(one))(rs).sum())

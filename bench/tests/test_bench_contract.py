@@ -66,6 +66,12 @@ def test_cell_resolves_by_name(cell):
     assert spec["cell"]["chips"] == spec["entry"]["chips"]
     assert spec["model"]["name"] == spec["entry"]["config"]
     assert set(spec["cell"]["limits"]) <= set(run.CHECK_NUMBERS)
+    family = run.family(spec)
+    for name in ("model_config", "make_inputs", "flops_per_sample"):
+        assert callable(getattr(family, name))
+    reference = run.reference(spec)
+    for name in ("init_params", "encode", "views"):
+        assert callable(getattr(reference, name))
     for m in spec["per_layer"]:
         reader = importlib.import_module("bench.metrics." + m["name"])
         assert callable(reader.read)

@@ -174,7 +174,7 @@ def _segment_op_names(spec, sharded=False):
         ecfg = ecfg._replace(cohort_axis="data", stats_kernel="off")
         mesh = Mesh(jax.devices()[:1], ("data",))
     engine = round_engine.RoundEngine(apply, opt, sampler, ecfg, mesh=mesh)
-    params = run.make_params(jax, spec["model"], 3)
+    params = run.make_params(jax, spec, 3)
     opt_state = jax.jit(opt.init)(params)
     rng = jnp.asarray(run.rounds_key(spec, 3))
     compiled = run.compiled_segment(jax, jnp, engine, params, opt_state, rng)

@@ -1,9 +1,11 @@
 """The one traffic generator: a cell's dataset and client partition, made
 from ``--seed`` and the parameters in ``bench/traffic/<traffic>.json``.
 
-The program under test receives only what this module makes (images,
-labels, client index and sizes); the plain reference in
-``bench/reference.py`` reads the same host arrays.
+The configuration's family (``bench/families/<family>.py``) calls a
+dataset generator here with its sample shape; the partition is the
+family's no concern. The program under test receives only what is made
+here (per-sample leaves, labels, client index and sizes); the plain
+reference (``bench/protocol.py``) reads the same host arrays.
 """
 from __future__ import annotations
 
@@ -24,16 +26,16 @@ def jax_key_data(seed: int, stream: str) -> np.ndarray:
 # -- datasets -----------------------------------------------------------------
 
 def prototype_images(rng: np.random.Generator, num_samples: int,
-                     num_classes: int, image_size: int, channels: int,
-                     noise: float):
-    """Class prototypes + per-sample noise, min-max scaled to [0, 1].
+                     num_classes: int, shape, noise: float):
+    """Class prototypes + per-sample noise, min-max scaled to [0, 1], for
+    images of ``shape`` (H, W, C).
     Returns (images (N, H*W*C) f32 rows, labels (N,) int32).
 
     Adapted from ``src/repro/data/synthetic.py::synthetic_labeled_images``:
     the same model of the data, drawn with a ``np.random.Generator``
     (float32 normals) and kept as (N, H*W*C) rows, the layout the program
     stages on the device."""
-    width = image_size * image_size * channels
+    width = int(np.prod(shape))
     protos = rng.standard_normal((num_classes, width), np.float32)
     labels = rng.integers(0, num_classes, num_samples)
     imgs = rng.standard_normal((num_samples, width), np.float32)
@@ -98,25 +100,25 @@ PARTITIONS = {"dirichlet": dirichlet, "dirichlet_quantity": dirichlet_quantity}
 class Traffic:
     """One cell's generated inputs."""
 
-    def __init__(self, images, labels, client_index, client_sizes,
-                 image_shape):
-        self.images = images                  # (N, H*W*C) f32, host
+    def __init__(self, leaves, shapes, labels, client_index, client_sizes):
+        self.leaves = leaves                  # name -> (N, elements), host
+        self.shapes = shapes                  # name -> one sample's shape
         self.labels = labels                  # (N,) int32
         self.client_index = client_index      # (clients, n_pad) int64
         self.client_sizes = client_sizes      # (clients,) int32
-        self.image_shape = image_shape        # (H, W, C)
 
     @property
     def num_clients(self) -> int:
         return self.client_index.shape[0]
 
 
-def make_dataset(spec: dict, model: dict, seed: int):
+def make_dataset(spec: dict, seed: int, **sample):
+    """The traffic's dataset from the seed; ``sample`` is what the family
+    says of one sample (for images, its ``shape``)."""
     d = dict(spec["dataset"])
     gen = DATASETS[d.pop("generator")]
     rng = np.random.Generator(np.random.PCG64(seed_sequence(seed, "data")))
-    return gen(rng, image_size=model["image_size"],
-               channels=model["resnet_in_channels"], **d)
+    return gen(rng, **sample, **d)
 
 
 def make_partition(spec: dict, labels, seed: int):
